@@ -18,6 +18,7 @@ import jax
 from repro.configs import ARCHS
 from repro.core import ClusterSpec, GB
 from repro.core.types import DFG, MB, TaskSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving import HostedModel, ServingCluster
 
@@ -63,6 +64,8 @@ def run(scheduler: str, requests, hosted_factory):
 
 
 def main() -> None:
+    enable_compile_cache()
+
     def hosted_factory():
         out = []
         for mid, arch in [

@@ -1,23 +1,15 @@
-"""Version-compat shims for the JAX surface this repo touches.
+"""The one ``shard_map`` entry point of this repo.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` to the top-level
-``jax`` namespace (and its replication-check kwarg was renamed
-``check_rep`` → ``check_vma``) across the JAX versions the container may
-carry.  Import it from here; the wrapper accepts the modern ``check_vma``
-keyword and translates for older installs.
+Callers import it from here so the keyword surface they rely on
+(``mesh``, ``in_specs``, ``out_specs``, ``check_vma``) is pinned in one
+place.
 """
 
 from __future__ import annotations
 
-import inspect
 from typing import Any, Callable
 
-try:  # jax >= 0.6: public top-level API
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_PARAMS = set(inspect.signature(_shard_map).parameters)
+import jax
 
 
 def shard_map(
@@ -28,9 +20,7 @@ def shard_map(
     out_specs: Any,
     check_vma: bool = True,
 ) -> Callable:
-    kw = {}
-    if "check_vma" in _PARAMS:
-        kw["check_vma"] = check_vma
-    elif "check_rep" in _PARAMS:
-        kw["check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma,
+    )

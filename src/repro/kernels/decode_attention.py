@@ -10,6 +10,10 @@ a skinny matmul that still feeds the MXU/VPU), and iterates KV blocks via
 the sequential minor grid dimension, carrying online-softmax statistics in
 VMEM scratch across grid steps.
 
+The cache is viewed as (B, T, KH·D) — a free reshape, no copy — so a KV
+head is a (block_k, D) column block: its last two dims meet the TPU's
+(8, 128) tiling whenever D is a multiple of 128 (or KH = 1).
+
 Invalid cache slots (≥ cache_len, ring-buffer tails) are masked with the
 per-batch length passed as a scalar-prefetch operand.
 
@@ -32,8 +36,8 @@ NEG_INF = -1e30
 def _dec_kernel(
     len_ref,  # scalar prefetch: (B,) int32 in SMEM
     q_ref,    # (1, 1, G, D)
-    k_ref,    # (1, block_k, 1, D)
-    v_ref,    # (1, block_k, 1, D)
+    k_ref,    # (1, block_k, D)
+    v_ref,    # (1, block_k, D)
     o_ref,    # (1, 1, G, D)
     m_ref, l_ref, acc_ref,  # VMEM scratch: (G, 1), (G, 1), (G, D)
     *,
@@ -52,29 +56,31 @@ def _dec_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32) * scale     # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)          # (bk, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0].astype(jnp.float32)                # (bk, D)
+    v = v_ref[0].astype(jnp.float32)
     s = q @ k.T                                     # (G, bk)
-    k_pos = ki * block_k + jax.lax.iota(jnp.int32, block_k)
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_k), 1
+    )
     valid = k_pos < jnp.minimum(len_ref[bi], kv_len)
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_ref[...][:, 0]
-    l_prev = l_ref[...][:, 0]
+    m_prev = m_ref[...]                             # (G, 1)
+    l_prev = l_ref[...]
     acc_prev = acc_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    l_new = alpha * l_prev + jnp.sum(p, axis=-1)
-    acc_new = acc_prev * alpha[:, None] + p @ v
-    m_ref[...] = m_new[:, None]
-    l_ref[...] = l_new[:, None]
+    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    acc_new = acc_prev * alpha + p @ v
+    m_ref[...] = m_new
+    l_ref[...] = l_new
     acc_ref[...] = acc_new
 
     @pl.when(ki == n_k - 1)
     def _finish():
         l = jnp.where(l_new == 0.0, 1.0, l_new)
-        o_ref[0, 0] = (acc_new / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_new / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -105,6 +111,8 @@ def decode_attention(
         v_cache = jnp.pad(v_cache, pad)
 
     qg = q.reshape(b, kh, group, d)  # queries grouped per KV head
+    kf = k_cache.reshape(b, t_pad, kh * d)  # KV head hi = column block hi
+    vf = v_cache.reshape(b, t_pad, kh * d)
     grid = (b, kh, pl.cdiv(t, block_k))
 
     out = pl.pallas_call(
@@ -119,10 +127,10 @@ def decode_attention(
                     (1, 1, group, d), lambda bi, hi, ki, *_: (bi, hi, 0, 0)
                 ),
                 pl.BlockSpec(
-                    (1, block_k, 1, d), lambda bi, hi, ki, *_: (bi, ki, hi, 0)
+                    (1, block_k, d), lambda bi, hi, ki, *_: (bi, ki, hi)
                 ),
                 pl.BlockSpec(
-                    (1, block_k, 1, d), lambda bi, hi, ki, *_: (bi, ki, hi, 0)
+                    (1, block_k, d), lambda bi, hi, ki, *_: (bi, ki, hi)
                 ),
             ],
             out_specs=pl.BlockSpec(
@@ -136,5 +144,5 @@ def decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((b, kh, group, d), q.dtype),
         interpret=interpret,
-    )(cache_len.astype(jnp.int32), qg, k_cache, v_cache)
+    )(cache_len.astype(jnp.int32), qg, kf, vf)
     return out.reshape(b, h, d)

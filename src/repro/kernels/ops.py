@@ -4,9 +4,8 @@ pure-jnp references.
 ``impl`` semantics:
   "ref"     — pure jnp (XLA-native).  Default for dry-runs / GSPMD lowering
               and the CPU container.
-  "pallas"  — the Pallas kernel, compiled for TPU.
-  "pallas_interpret" — the Pallas kernel body executed in Python on CPU
-              (correctness validation; used by the kernel tests).
+  "pallas"  — the Pallas kernel, compiled for TPU.  (The kernel tests
+              call the kernels directly in interpret mode.)
   "auto"    — pallas on TPU backends, ref elsewhere.
 """
 
@@ -21,16 +20,9 @@ import jax.numpy as jnp
 from repro.kernels import ref as _ref
 
 
-def _backend_is_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
-
-
 def _resolve(impl: str) -> str:
     if impl == "auto":
-        return "pallas" if _backend_is_tpu() else "ref"
+        return "pallas" if jax.default_backend() == "tpu" else "ref"
     return impl
 
 
@@ -71,7 +63,6 @@ def flash_attention(
         causal=causal,
         window=window,
         q_offset=q_offset,
-        interpret=(impl == "pallas_interpret"),
     )
 
 
@@ -91,9 +82,7 @@ def decode_attention(
         return _ref.decode_attention_grouped_ref(q, k_cache, v_cache, cache_len)
     from repro.kernels import decode_attention as _da
 
-    return _da.decode_attention(
-        q, k_cache, v_cache, cache_len, interpret=(impl == "pallas_interpret")
-    )
+    return _da.decode_attention(q, k_cache, v_cache, cache_len)
 
 
 # -- Mamba2 SSD scan ---------------------------------------------------------------
@@ -126,7 +115,6 @@ def ssd_scan(
         x, dt, a, b, c,
         initial_state=initial_state,
         chunk=chunk,
-        interpret=(impl == "pallas_interpret"),
     )
 
 
@@ -155,6 +143,4 @@ def moe_gmm(
         return _ref.moe_gmm_ref(x, w, group_sizes)
     from repro.kernels import moe_gmm as _gmm
 
-    return _gmm.moe_gmm(
-        x, w, group_sizes, interpret=(impl == "pallas_interpret")
-    )
+    return _gmm.moe_gmm(x, w, group_sizes)

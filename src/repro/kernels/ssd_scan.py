@@ -17,9 +17,15 @@ sequential minor grid dimension.
 
 Grid: (batch, heads, T/chunk) — the chunk dimension iterates sequentially
 (TPU grids are lexicographic), so the scratch state persists chunk→chunk.
+The wrapper puts the head axis before time, (B, H, T, ·), so every block
+ends in (chunk, P | N) or (1, chunk); on the chip ``chunk`` must therefore
+be a multiple of 128 or cover the whole (padded) sequence.
 
-VMEM per program (chunk = 128, P = 64, N = 128, f32):
-  x (128×64) + b,c (2×128×128) + Γ (128×128) + state (64×128) ≈ 230 KB.
+VMEM per program (chunk = 128, P = 64, N = 128): double-buffered blocks
+x, y (2×2×128×64) + b, c (2×2×128×128) + dt, f32 ≈ 0.4 MB, the state
+scratch and final-state block (2×64×128×4 B) and about six (128×128) f32
+temporaries ≈ 0.5 MB — about 1 MB against v5e's 16 MiB default scoped
+VMEM.
 """
 
 from __future__ import annotations
@@ -34,12 +40,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssd_kernel(
-    x_ref,    # (1, chunk, 1, P)
-    dt_ref,   # (1, chunk, 1)
-    a_ref,    # (1,)
-    b_ref,    # (1, chunk, 1, N)
-    c_ref,    # (1, chunk, 1, N)
-    y_ref,    # (1, chunk, 1, P)
+    x_ref,    # (1, 1, chunk, P)
+    dt_ref,   # (1, 1, 1, chunk)
+    a_ref,    # (H,) in SMEM
+    b_ref,    # (1, 1, chunk, N)
+    c_ref,    # (1, 1, chunk, N)
+    y_ref,    # (1, 1, chunk, P)
     fs_ref,   # final state out: (1, 1, P, N)
     state_ref,  # VMEM scratch: (P, N) carried across chunks
     *,
@@ -53,36 +59,47 @@ def _ssd_kernel(
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    x = x_ref[0, :, 0].astype(jnp.float32)    # (L, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)  # (L,)
-    a = a_ref[0].astype(jnp.float32)          # scalar
-    b = b_ref[0, :, 0].astype(jnp.float32)    # (L, N)
-    c = c_ref[0, :, 0].astype(jnp.float32)    # (L, N)
+    x = x_ref[0, 0].astype(jnp.float32)       # (L, P)
+    dt = dt_ref[0, 0].astype(jnp.float32)     # (1, L) row
+    a = a_ref[pl.program_id(1)].astype(jnp.float32)  # scalar
+    b = b_ref[0, 0].astype(jnp.float32)       # (L, N)
+    c = c_ref[0, 0].astype(jnp.float32)       # (L, N)
 
     # Zero padded steps so they neither decay nor inject state.
-    t_pos = ci * chunk + jax.lax.iota(jnp.int32, chunk)
-    valid = t_pos < seq_len
-    dt = jnp.where(valid, dt, 0.0)
+    t_pos = ci * chunk + jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+    dt = jnp.where(t_pos < seq_len, dt, 0.0)
 
-    s = jnp.cumsum(a * dt)                    # (L,) cumulative log-decay
+    # Everything stays 2-D (the TPU lowering has no cumsum and tiles
+    # vectors as (sublane, lane)): the prefix sum is a masked reduction.
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = row >= col
+    dt_all = jnp.broadcast_to(dt, (chunk, chunk))   # [i, j] = dt_j
+    dt_col = jnp.sum(jnp.where(row == col, dt_all, 0.0), axis=1,
+                     keepdims=True)                   # (L, 1)
+    adt = jnp.broadcast_to(a * dt, (chunk, chunk))  # [i, j] = a·dt_j
+    adt_col = jnp.broadcast_to(a * dt_col, (chunk, chunk))  # [i, j] = a·dt_i
+    # s_i = Σ_{j ≤ i} a·dt_j, as a column and as a row.
+    s_col = jnp.sum(jnp.where(causal, adt, 0.0), axis=1, keepdims=True)
+    s_row = jnp.sum(jnp.where(row <= col, adt_col, 0.0), axis=0,
+                    keepdims=True)
     # Γ_ij = exp(s_i - s_j) · dt_j · [j ≤ i]
-    li = jax.lax.iota(jnp.int32, chunk)
-    causal = li[:, None] >= li[None, :]
-    gamma = jnp.where(causal, jnp.exp(s[:, None] - s[None, :]), 0.0)
-    gamma = gamma * dt[None, :]
+    gamma = jnp.where(causal, jnp.exp(s_col - s_row), 0.0) * dt
 
     state_in = state_ref[...]                 # (P, N)
     # Intra-chunk (dual/attention form): ((C Bᵀ) ⊙ Γ) X
     cb = c @ b.T                              # (L, L)
     y_intra = (cb * gamma) @ x                # (L, P)
     # Inter-chunk: decayed input state read out by C.
-    y_inter = jnp.exp(s)[:, None] * (c @ state_in.T)  # (L, P)
-    y_ref[0, :, 0] = (y_intra + y_inter).astype(y_ref.dtype)
+    y_inter = jnp.exp(s_col) * (c @ state_in.T)  # (L, P)
+    y_ref[0, 0] = (y_intra + y_inter).astype(y_ref.dtype)
 
     # State update: S_out = exp(s_L)·S_in + Σ_j exp(s_L - s_j)·dt_j·(x_j ⊗ b_j)
-    decay_all = jnp.exp(s[-1])
-    w = jnp.exp(s[-1] - s) * dt               # (L,)
-    state_new = decay_all * state_in + (x * w[:, None]).T @ b  # (P, N)
+    s_last = s_col[chunk - 1:, :]             # (1, 1)
+    w = jnp.exp(s_last - s_col) * dt_col      # (L, 1)
+    state_new = jnp.exp(s_last) * state_in + jax.lax.dot_general(
+        x * w, b, (((0,), (0,)), ((), ()))
+    )                                         # (P, N)
     state_ref[...] = state_new
 
     @pl.when(ci == n_c - 1)
@@ -126,26 +143,32 @@ def ssd_scan(
         b = jnp.pad(b, pad3 + ((0, 0),))
         c = jnp.pad(c, pad3 + ((0, 0),))
 
+    # Heads lead and time is second-minor, so every block's last two dims
+    # are (chunk, P|N) or (1, chunk): TPU (8, 128) tiling.
+    xt = x.transpose(0, 2, 1, 3)                 # (B, H, T, P)
+    dtt = dt.transpose(0, 2, 1)[:, :, None, :]   # (B, H, 1, T)
+    bt = b.transpose(0, 2, 1, 3)                 # (B, H, T, N)
+    ct = c.transpose(0, 2, 1, 3)
     grid = (bsz, h, t_pad // chunk)
     y, fs = pl.pallas_call(
         functools.partial(_ssd_kernel, chunk=chunk, seq_len=t),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda bi, hi, ci: (bi, ci, hi)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
-            pl.BlockSpec((1, chunk, 1, n), lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, chunk, 1, n), lambda bi, hi, ci: (bi, ci, hi, 0)),
+            pl.BlockSpec((1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda bi, hi, ci: (bi, hi, 0, ci)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, chunk, n), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((1, 1, chunk, n), lambda bi, hi, ci: (bi, hi, ci, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
+            pl.BlockSpec((1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
             pl.BlockSpec((1, 1, p, n), lambda bi, hi, ci: (bi, hi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, t_pad, h, p), x.dtype),
+            jax.ShapeDtypeStruct((bsz, h, t_pad, p), x.dtype),
             jax.ShapeDtypeStruct((bsz, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(x, dt, a, b, c)
-    return y[:, :t], fs
+    )(xt, dtt, a, bt, ct)
+    return y[:, :, :t].transpose(0, 2, 1, 3), fs

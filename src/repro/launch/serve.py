@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.models import init_cache, init_params
 from repro.training import make_serve_step
@@ -32,6 +33,7 @@ def main() -> None:
     ap.add_argument("--optimized", action="store_true",
                     help="§Perf serving path (grouped decode, onehot writes)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:

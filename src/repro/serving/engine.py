@@ -3,16 +3,16 @@ jitted JAX models.
 
 This is the execution-engine layer of the paper's system (§3): each
 *worker* hosts an accelerator-memory model cache (``GpuMemoryManager``)
-and an execution queue; the Navigator scheduler (vectorized JAX planner +
-Alg. 2 adjustment) places pipeline tasks; the Execution Engine performs
-real ``prefill`` + autoregressive ``decode_step`` calls on the zoo models.
+and an execution queue; the scheduler from ``make_scheduler`` (Alg. 1
+planning on the host; the served path runs no Alg. 2 adjustment) places
+pipeline tasks; the Execution Engine performs real teacher-forced prefill
++ autoregressive ``decode_step`` calls on the zoo models.
 
-On the CPU container every worker shares one physical device, so transfer
-and fetch *costs* advance a virtual clock from the profiled cost model
-(exactly the simulator's), while the ML compute itself is real —
-logits-level real outputs, wall-clock measured.  On a TPU deployment the
-same engine binds workers to devices and the virtual costs become real
-device transfers.
+Every worker shares one device — ``jax.devices()[0]``, a CPU in the tests
+and one TPU chip under ``chip_smoke.py``.  Transfer and fetch *costs*
+therefore advance a virtual clock from the profiled cost model (exactly
+the simulator's), while the ML compute itself is real: real outputs,
+wall-clock measured.
 """
 
 from __future__ import annotations
@@ -67,7 +67,9 @@ class ExecutionEngine:
         self.decode_tokens = decode_tokens
         self._steps: Dict[int, Callable] = {}
 
-    def _get_step(self, mid: int):
+    def decode_fn(self, mid: int) -> Callable:
+        """The jitted one-token step ``(params, cache, tokens) -> (logits,
+        cache)`` this engine runs for model ``mid``."""
         if mid not in self._steps:
             cfg = self.models[mid].cfg
             self._steps[mid] = jax.jit(
@@ -84,7 +86,7 @@ class ExecutionEngine:
         t0 = time.perf_counter()
         b, s = prompt.shape
         cache = init_cache(cfg, b, capacity=s + self.decode_tokens + 1)
-        step = self._get_step(mid)
+        step = self.decode_fn(mid)
         toks = jnp.asarray(prompt)
         out = []
         # teacher-forced prefill through the decode path (seeds the cache)
