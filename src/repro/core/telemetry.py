@@ -29,6 +29,13 @@ than end-of-run aggregates.  This module is the instrument:
   the engines' ad-hoc result counters into a stable, versioned export
   schema (``schemas/metrics.schema.json``).
 
+* **Wall-clock spans** — the served path (``serving/engine.py``) also
+  records :class:`WallSpan`s on ``time.perf_counter``: request, plan,
+  state, task and its phases.  They sit in ``wall_spans`` beside the
+  event rings, not in them, so the virtual-clock taxonomy and the JSONL
+  stream stay as they are; ``to_chrome_trace`` draws them on a process of
+  their own.
+
 Zero overhead when off: the engines guard every emission site with
 ``if self._rec is not None`` and never call into this module from the
 hot event loop while tracing is disabled — the CI ``trace-smoke`` guard
@@ -50,6 +57,7 @@ from typing import (
     Iterable,
     List,
     Mapping,
+    NamedTuple,
     Optional,
     Sequence,
     Tuple,
@@ -280,6 +288,18 @@ class PlacementDecision:
 # --------------------------------------------------------------------------
 # Flight recorder
 # --------------------------------------------------------------------------
+class WallSpan(NamedTuple):
+    """One span of the served path on the host's wall clock
+    (``time.perf_counter`` seconds).  ``parent`` is the index in
+    ``FlightRecorder.wall_spans`` of the span open around it, or -1."""
+
+    name: str
+    t0: float
+    t1: Optional[float]
+    parent: int
+    stats: Dict[str, Any]
+
+
 @dataclasses.dataclass(frozen=True)
 class TraceConfig:
     """Flight-recorder tunables."""
@@ -314,6 +334,9 @@ class FlightRecorder:
         self._seq = 0
         self.placements: List[PlacementDecision] = []
         self._placement_index: Dict[Tuple[int, str], List[int]] = {}
+        # Served-path wall-clock spans, in the order they opened.
+        self.wall_spans: List[WallSpan] = []
+        self._open_spans: List[int] = []
 
     # -- hot path ------------------------------------------------------------
     def emit(self, t: float, kind: str, worker: int = GLOBAL, **data) -> None:
@@ -342,6 +365,16 @@ class FlightRecorder:
             chosen=decision.chosen,
             n_candidates=len(decision.candidates),
         )
+
+    # -- wall-clock spans (the served path calls these) ---------------------
+    def begin_span(self, name: str, t0: float, stats: Dict[str, Any]) -> None:
+        parent = self._open_spans[-1] if self._open_spans else -1
+        self._open_spans.append(len(self.wall_spans))
+        self.wall_spans.append(WallSpan(name, t0, None, parent, stats))
+
+    def end_span(self, t1: float) -> None:
+        i = self._open_spans.pop()
+        self.wall_spans[i] = self.wall_spans[i]._replace(t1=t1)
 
     def decisions(self, job_id: int, task_id: str) -> List[PlacementDecision]:
         return [
@@ -397,7 +430,9 @@ class FlightRecorder:
         """Chrome-trace/Perfetto JSON (``chrome://tracing`` object
         format).  pid = worker, tids split execution / fetch-pipe /
         network lanes; instant events carry churn and scheduling
-        markers."""
+        markers.  All of that is on the virtual clock; the served path's
+        wall-clock spans go on a process of their own (pid n_workers + 1),
+        timed from the first of them."""
         US = 1e6
         tev: List[Dict[str, Any]] = []
         for w in range(self.n_workers):
@@ -464,6 +499,21 @@ class FlightRecorder:
                     "pid": pid, "tid": 0, "ts": t * US,
                     "args": {k: v for k, v in sorted(data.items())
                              if isinstance(v, (int, float, str, bool))},
+                })
+        if self.wall_spans:
+            pid = self.n_workers + 1
+            tev.append({"ph": "M", "name": "process_name", "pid": pid,
+                        "tid": 0,
+                        "args": {"name": "served path (wall clock)"}})
+            base = self.wall_spans[0].t0
+            for i, sp in enumerate(self.wall_spans):
+                if sp.t1 is None:
+                    continue
+                tev.append({
+                    "ph": "X", "cat": "wall", "name": sp.name, "pid": pid,
+                    "tid": 0, "ts": (sp.t0 - base) * US,
+                    "dur": (sp.t1 - sp.t0) * US,
+                    "args": dict(sp.stats, span=i, parent=sp.parent),
                 })
         return {
             "schema_version": TRACE_SCHEMA_VERSION,

@@ -13,13 +13,20 @@ and one TPU chip under ``chip_smoke.py``.  Transfer and fetch *costs*
 therefore advance a virtual clock from the profiled cost model (exactly
 the simulator's), while the ML compute itself is real: real outputs,
 wall-clock measured.
+
+``ServedSpans`` times the served path on the wall clock: request, plan,
+state, task and the task's phases, each a ``compass.<name>`` profiler
+annotation (on the clock of the device trace) and, with ``trace`` on, a
+``WallSpan`` in the flight recorder.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
+from typing import (Any, Callable, Dict, List, Optional, Sequence, Set, Tuple,
+                    Union)
 
 import jax
 import jax.numpy as jnp
@@ -59,48 +66,107 @@ class HostedModel:
         )
 
 
+class ServedSpans:
+    """Wall-clock spans of the served path.
+
+    ``spans(name, **stats)`` opens ``compass.<name>``: always as a
+    ``jax.profiler.TraceAnnotation`` (about a microsecond with no profiler
+    running), so the span lies on the device trace's clock; with a
+    recorder (``ServingCluster(trace=...)``) also as a ``WallSpan`` in
+    ``recorder.wall_spans``.  ``labels`` (the request's job id, and the
+    task and worker while one runs) go into every span's stats.
+    """
+
+    def __init__(self, recorder: Optional[FlightRecorder] = None) -> None:
+        self.recorder = recorder
+        self.labels: Dict[str, Any] = {}
+
+    @contextlib.contextmanager
+    def __call__(self, name: str, **stats):
+        """Yields ``[t0, t1]``, the span's ``time.perf_counter`` reads;
+        ``t1`` is filled in when it closes."""
+        stats = {**self.labels, **stats}
+        rec = self.recorder
+        with jax.profiler.TraceAnnotation("compass." + name, **stats):
+            clock = [time.perf_counter(), 0.0]
+            if rec is not None:
+                rec.begin_span(name, clock[0], stats)
+            try:
+                yield clock
+            finally:
+                clock[1] = time.perf_counter()
+                if rec is not None:
+                    rec.end_span(clock[1])
+
+
 class ExecutionEngine:
     """Per-framework plug-in layer (§3): here, one plug-in — JAX."""
 
-    def __init__(self, models: Dict[int, HostedModel], decode_tokens: int = 8):
+    def __init__(self, models: Dict[int, HostedModel], decode_tokens: int = 8,
+                 spans: Optional[ServedSpans] = None):
         self.models = models
         self.decode_tokens = decode_tokens
+        self.spans = spans if spans is not None else ServedSpans()
         self._steps: Dict[int, Callable] = {}
+        # (model, batch, cache capacity) of every step shape run so far
+        self._shapes_run: Set[Tuple[int, int, int]] = set()
 
     def decode_fn(self, mid: int) -> Callable:
         """The jitted one-token step ``(params, cache, tokens) -> (logits,
-        cache)`` this engine runs for model ``mid``."""
+        cache)`` this engine runs for model ``mid``.  Its program is named
+        ``jit_served_decode_step`` in compiled text and device traces."""
         if mid not in self._steps:
             cfg = self.models[mid].cfg
-            self._steps[mid] = jax.jit(
-                lambda p, c, t, cfg=cfg: decode_step(p, c, t, cfg,
-                                                     moe_dispatch="scan")
-            )
+
+            def served_decode_step(params, cache, tokens):
+                return decode_step(params, cache, tokens, cfg,
+                                   moe_dispatch="scan")
+
+            self._steps[mid] = jax.jit(served_decode_step)
         return self._steps[mid]
+
+    def _first_call(self, mid: int, batch: int, capacity: int):
+        """A span around the first step call of a shape this engine has
+        not run before (the call that compiles), else nothing."""
+        key = (mid, batch, capacity)
+        if key in self._shapes_run:
+            return contextlib.nullcontext()
+        self._shapes_run.add(key)
+        return self.spans("first_call", capacity=capacity)
 
     def run_task(self, mid: int, prompt: np.ndarray) -> Tuple[np.ndarray, float]:
         """Prefill ``prompt`` then greedily decode a few tokens.  Returns
-        (generated token ids, wall seconds)."""
+        (generated token ids, wall seconds of the task span)."""
         hosted = self.models[mid]
-        cfg = hosted.cfg
-        t0 = time.perf_counter()
+        params = hosted.params
         b, s = prompt.shape
-        cache = init_cache(cfg, b, capacity=s + self.decode_tokens + 1)
+        d = self.decode_tokens
+        cap = s + d + 1
+        sp = self.spans
         step = self.decode_fn(mid)
-        toks = jnp.asarray(prompt)
-        out = []
-        # teacher-forced prefill through the decode path (seeds the cache)
-        for i in range(s):
-            logits, cache = step(hosted.params, cache, toks[:, i])
-        nxt = jnp.argmax(logits, axis=-1)
-        for _ in range(self.decode_tokens):
-            out.append(nxt)
-            logits, cache = step(hosted.params, cache, nxt)
-            nxt = jnp.argmax(logits, axis=-1)
-        jax.block_until_ready(logits)
-        return np.stack([np.asarray(o) for o in out], axis=1), (
-            time.perf_counter() - t0
-        )
+        with sp("task", model=mid, prompt=s, decode=d) as clock:
+            with sp("task_setup", capacity=cap):
+                cache = init_cache(hosted.cfg, b, capacity=cap)
+                toks = jnp.asarray(prompt)
+            # teacher-forced prefill through the decode path (seeds the
+            # cache); the spans time the dispatch, the device runs later
+            with sp("prefill", calls=s):
+                with self._first_call(mid, b, cap):
+                    logits, cache = step(params, cache, toks[:, 0])
+                for i in range(1, s):
+                    logits, cache = step(params, cache, toks[:, i])
+            out = []
+            with sp("decode", calls=d):
+                nxt = jnp.argmax(logits, axis=-1)
+                for _ in range(d):
+                    out.append(nxt)
+                    logits, cache = step(params, cache, nxt)
+                    nxt = jnp.argmax(logits, axis=-1)
+            with sp("sync"):
+                jax.block_until_ready(logits)
+            with sp("readback", copies=len(out)):
+                tokens = np.stack([np.asarray(o) for o in out], axis=1)
+        return tokens, clock[1] - clock[0]
 
 
 @dataclasses.dataclass
@@ -174,7 +240,10 @@ class ServingCluster:
             )
             for w in cluster.workers()
         ]
-        self.engine = ExecutionEngine(self.hosted, decode_tokens)
+        # Wall-clock spans of the served path: always profiler
+        # annotations, kept in the recorder too when tracing is on.
+        self.spans = ServedSpans(self.recorder)
+        self.engine = ExecutionEngine(self.hosted, decode_tokens, self.spans)
         self._vclock = [0.0] * cluster.n_workers  # per-worker virtual time
         # Predictive prefetch plane (core/prefetch.py) on the virtual
         # clock: planned intents stage models through the per-worker fetch
@@ -210,11 +279,37 @@ class ServingCluster:
         now = max(self._vclock)
         job = Job(self._jobid, dfg, arrival_time=now)
         self._jobid += 1
+        sp = self.spans
+        sp.labels = {"job": job.job_id}
+        try:
+            with sp("request", dfg=dfg.name,
+                    tasks=len(dfg.tasks)) as clock:
+                outputs, adfg, finish = self._serve(job, dfg, inputs, origin,
+                                                    now)
+        finally:
+            sp.labels = {}
+        result = RequestResult(
+            job_id=job.job_id,
+            dfg_name=dfg.name,
+            latency_s=clock[1] - clock[0],
+            virtual_latency_s=max(finish.values()) - now,
+            outputs=outputs,
+            assignment=dict(adfg.assignment),
+        )
+        self.results.append(result)
+        return result
+
+    def _serve(self, job: Job, dfg: DFG, inputs: Dict[str, np.ndarray],
+               origin: int, now: float):
+        """``submit``'s body: plan ``job`` at virtual time ``now`` and run
+        its tasks.  Returns (outputs, assignment, virtual finish times)."""
+        sp = self.spans
         if self.gossip is not None:
             # Run the gossip rounds due up to the request's arrival; the
             # origin worker then plans from its own (possibly stale) view.
             self.sst.advance(now)
-        adfg = self.scheduler.plan(job, now, origin, self.sst.view(origin))
+        with sp("plan"):
+            adfg = self.scheduler.plan(job, now, origin, self.sst.view(origin))
         if adfg is None:
             raise NotImplementedError("serving engine drives planned schedulers")
         if self.prefetch_plane is not None:
@@ -226,13 +321,13 @@ class ServingCluster:
             rec.emit(now, "job.arrive", job=job.job_id,
                      dfg=dfg.name, origin=origin, n_tasks=len(dfg.tasks))
 
-        wall0 = time.perf_counter()
         outputs: Dict[str, np.ndarray] = {}
         finish: Dict[str, float] = {}
         for ti, tid in enumerate(dfg.topo_order):
             task = dfg.tasks[tid]
             w = adfg[tid]
             mem = self.memories[w]
+            sp.labels = {"job": job.job_id, "task": tid, "worker": w}
             start = max(
                 self._vclock[w],
                 max((finish[p] for p in dfg.preds[tid]), default=now),
@@ -266,64 +361,67 @@ class ServingCluster:
                                  frm=adfg[p], to=w, arrive=arrive)
             was_miss = False
             if task.model_id is not None:
-                upcoming = [task.model_id]
-                res = mem.ensure(task.model_id, upcoming)
-                ready = (
-                    self._prefetch_ready_at[w].pop(task.model_id, None)
-                    if self.prefetch_plane is not None
-                    else None
-                )
-                if res is not None:
-                    fetch_s, _ = res
-                    was_miss = fetch_s > 0.0
-                    if rec is not None and fetch_s > 0.0:
-                        rec.emit(start, "fetch.start", worker=w,
-                                 fetch_kind="demand", model=task.model_id,
-                                 bytes=mem.cached_size(task.model_id),
-                                 dur=fetch_s, job=job.job_id, task=tid)
-                        rec.emit(start + fetch_s, "fetch.done", worker=w,
-                                 model=task.model_id, spec=False)
-                    if self.health is not None and fetch_s > 0.0:
-                        self.health.fetch_state(w, start, True)
-                        self.health.fetch_state(w, start + fetch_s, False)
-                    if fetch_s > 0.0 and self.prefetch_plane is not None:
-                        # Demand miss: demand preempts speculation on the
-                        # single fetch pipe — the transfer starts now, and
-                        # every speculative transfer still in flight is
-                        # pushed back behind it.
-                        t0 = start
-                        start += fetch_s
-                        self._pipe_free_at[w] = max(
-                            self._pipe_free_at[w] + fetch_s, start
+                with sp("state"):
+                    upcoming = [task.model_id]
+                    res = mem.ensure(task.model_id, upcoming)
+                    ready = (
+                        self._prefetch_ready_at[w].pop(task.model_id, None)
+                        if self.prefetch_plane is not None
+                        else None
+                    )
+                    if res is not None:
+                        fetch_s, _ = res
+                        was_miss = fetch_s > 0.0
+                        if rec is not None and fetch_s > 0.0:
+                            rec.emit(start, "fetch.start", worker=w,
+                                     fetch_kind="demand", model=task.model_id,
+                                     bytes=mem.cached_size(task.model_id),
+                                     dur=fetch_s, job=job.job_id, task=tid)
+                            rec.emit(start + fetch_s, "fetch.done", worker=w,
+                                     model=task.model_id, spec=False)
+                        if self.health is not None and fetch_s > 0.0:
+                            self.health.fetch_state(w, start, True)
+                            self.health.fetch_state(w, start + fetch_s,
+                                                    False)
+                        if fetch_s > 0.0 and self.prefetch_plane is not None:
+                            # Demand miss: demand preempts speculation on the
+                            # single fetch pipe — the transfer starts now, and
+                            # every speculative transfer still in flight is
+                            # pushed back behind it.
+                            t0 = start
+                            start += fetch_s
+                            self._pipe_free_at[w] = max(
+                                self._pipe_free_at[w] + fetch_s, start
+                            )
+                            for m, t in self._prefetch_ready_at[w].items():
+                                if t > t0:
+                                    self._prefetch_ready_at[w][m] = t + fetch_s
+                        elif fetch_s > 0.0:
+                            start += fetch_s
+                        elif ready is not None:
+                            # Cache hit thanks to a speculative transfer that
+                            # may still be in flight on the virtual clock.
+                            start = max(start, ready)
+                            if rec is not None:
+                                rec.emit(start, "fetch.promote", worker=w,
+                                         model=task.model_id, job=job.job_id,
+                                         task=tid)
+                    self.sst.update_cache(w, mem.bitmap, mem.free_bytes, start)
+                    if self.health is not None:
+                        self.health.sample_memory(
+                            w, start,
+                            (mem.used_bytes + mem.exec_reserved_bytes)
+                            / mem.capacity_bytes
+                            if mem.capacity_bytes > 0 else 0.0,
+                            mem.stats.evictions,
                         )
-                        for m, t in self._prefetch_ready_at[w].items():
-                            if t > t0:
-                                self._prefetch_ready_at[w][m] = t + fetch_s
-                    elif fetch_s > 0.0:
-                        start += fetch_s
-                    elif ready is not None:
-                        # Cache hit thanks to a speculative transfer that
-                        # may still be in flight on the virtual clock.
-                        start = max(start, ready)
-                        if rec is not None:
-                            rec.emit(start, "fetch.promote", worker=w,
-                                     model=task.model_id, job=job.job_id,
-                                     task=tid)
-                self.sst.update_cache(w, mem.bitmap, mem.free_bytes, start)
-                if self.health is not None:
-                    self.health.sample_memory(
-                        w, start,
-                        (mem.used_bytes + mem.exec_reserved_bytes)
-                        / mem.capacity_bytes
-                        if mem.capacity_bytes > 0 else 0.0,
-                        mem.stats.evictions,
-                    )
-                if self.prefetch_plane is not None:
-                    self.sst.update_intent(
-                        w,
-                        mem.bitmap | self.prefetch_plane.advertised_bits(w),
-                        start,
-                    )
+                    if self.prefetch_plane is not None:
+                        self.sst.update_intent(
+                            w,
+                            mem.bitmap
+                            | self.prefetch_plane.advertised_bits(w),
+                            start,
+                        )
                 prompt = self._task_input(tid, dfg, inputs, outputs)
                 out, wall = self.engine.run_task(task.model_id, prompt)
                 outputs[tid] = out
@@ -336,53 +434,47 @@ class ServingCluster:
                 ) if preds else np.zeros((1, 0), np.int32)
                 runtime = 1e-4
             finish[tid] = start + runtime
-            if rec is not None:
-                rec.emit(start, "task.start", worker=w, job=job.job_id,
-                         task=tid, gen=0,
-                         model=-1 if task.model_id is None else task.model_id,
-                         miss=was_miss)
-                rec.emit(finish[tid], "task.done", worker=w, job=job.job_id,
-                         task=tid, gen=0)
-            self._vclock[w] = finish[tid]
-            self.sst.update_load(w, self._vclock[w], finish[tid])
-            if self.health is not None:
-                # Virtual-queue depth: this job's tasks still bound to w
-                # (including the one just finished draining to 0 marks
-                # the backlog the next probe would see).
-                depth = sum(
-                    1 for t2 in dfg.topo_order[ti + 1:] if adfg[t2] == w
-                )
-                self.health.sample_queue(w, finish[tid], depth)
-                self.health.task_done(
-                    w, finish[tid], runtime,
-                    self.profiles.runtime(task, w),
-                )
-                # Digest refresh rides the publication, same as the sim.
-                d = self.health.digest(w, finish[tid])
-                self.sst.update_health(
-                    w, d.queue_depth, d.mem_occupancy, d.fetch_util,
-                    d.p99_latency_s, finish[tid],
-                )
-            if self.gossip is not None:
-                self.sst.advance(finish[tid])
-            else:
-                self.sst.push(w, finish[tid])
+            with sp("state"):
+                if rec is not None:
+                    rec.emit(start, "task.start", worker=w, job=job.job_id,
+                             task=tid, gen=0,
+                             model=-1 if task.model_id is None
+                             else task.model_id,
+                             miss=was_miss)
+                    rec.emit(finish[tid], "task.done", worker=w,
+                             job=job.job_id, task=tid, gen=0)
+                self._vclock[w] = finish[tid]
+                self.sst.update_load(w, self._vclock[w], finish[tid])
+                if self.health is not None:
+                    # Virtual-queue depth: this job's tasks still bound to
+                    # w (including the one just finished draining to 0
+                    # marks the backlog the next probe would see).
+                    depth = sum(
+                        1 for t2 in dfg.topo_order[ti + 1:] if adfg[t2] == w
+                    )
+                    self.health.sample_queue(w, finish[tid], depth)
+                    self.health.task_done(
+                        w, finish[tid], runtime,
+                        self.profiles.runtime(task, w),
+                    )
+                    # Digest refresh rides the publication, same as the sim.
+                    d = self.health.digest(w, finish[tid])
+                    self.sst.update_health(
+                        w, d.queue_depth, d.mem_occupancy, d.fetch_util,
+                        d.p99_latency_s, finish[tid],
+                    )
+                if self.gossip is not None:
+                    self.sst.advance(finish[tid])
+                else:
+                    self.sst.push(w, finish[tid])
+            sp.labels = {"job": job.job_id}
         t_end = max(finish.values())
         if rec is not None:
             rec.emit(t_end, "job.done", job=job.job_id,
                      latency=t_end - now)
         if self.health is not None:
             self.health.job_done(t_end, t_end - now)
-        result = RequestResult(
-            job_id=job.job_id,
-            dfg_name=dfg.name,
-            latency_s=time.perf_counter() - wall0,
-            virtual_latency_s=max(finish.values()) - now,
-            outputs=outputs,
-            assignment=dict(adfg.assignment),
-        )
-        self.results.append(result)
-        return result
+        return outputs, adfg, finish
 
     def _issue_prefetches(self, job: Job, adfg, now: float) -> None:
         """Virtual-clock analogue of the simulator's speculative fetch
