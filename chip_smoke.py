@@ -156,7 +156,8 @@ def serve_phase(
     ref = np.asarray(fwd(params, prompts[0])[:, -1], np.float32)
     ref_compile_s = time.perf_counter() - t0
     # The served path's logits: the engine's step, teacher-forced over the
-    # same prompt exactly as ``run_task`` prefills it.
+    # same prompt exactly as ``run_task`` prefills a model of a family other
+    # than dense (mamba2-780m here; a dense model prefills in one call).
     toks = jnp.asarray(prompts[0])
     cache = init_cache(cfg, batch, capacity=prompt_len + decode_tokens + 1)
     for i in range(prompt_len):

@@ -38,6 +38,11 @@ from repro.models.layers import (
 Params = Dict[str, Any]
 Cache = Dict[str, Any]
 
+# Families whose cache ``prefill`` fills in one call; the others' caches (a
+# final scan state, a latent cache) need a fill of their own, so the served
+# path feeds their prompts through ``decode_step`` one position per call.
+PREFILL_FAMILIES = frozenset({"dense"})
+
 
 def _layer_scan(body, carry, xs, unroll: bool):
     """lax.scan over the layer stack, or a Python loop when ``unroll`` —
@@ -255,6 +260,36 @@ def _dense_block(h, layer, positions, cfg, *, window, impl, mrope=False,
     return h, kv
 
 
+def _dense_layers(params, h, positions, cfg, *, window, impl, kv,
+                  mrope_positions=None, unroll=False):
+    """The dense layer stack over ``h`` (B, S, D).  Returns ``(h, kvs)``:
+    with ``kv`` each layer's rope-applied ``(k, v)``, stacked over layers,
+    else None."""
+    def body(hh, layer):
+        hh, layer_kv = _dense_block(
+            hh, layer, positions, cfg, window=window, impl=impl,
+            mrope=cfg.use_mrope, mrope_positions=mrope_positions,
+            unroll=unroll,
+        )
+        return hh, layer_kv if kv else None
+
+    return _layer_scan(body, h, params["layers"], unroll)
+
+
+def _embed(params, tokens):
+    """Token embeddings (B, S, D) and positions 0..S-1 (B, S)."""
+    bsz, s = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(s)[None, :], (bsz, s))
+    return params["embed"][tokens], positions
+
+
+def _logits(params, h, cfg):
+    """Final norm and the output head (the embedding table when tied)."""
+    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    return h @ head
+
+
 def _moe_block(h, layer, positions, cfg, *, window, impl, dispatch,
                mesh=None, unroll=False):
     if cfg.use_mla:
@@ -322,8 +357,7 @@ def forward(
     at = cfg.arch_type
     tokens = batch["tokens"]
     bsz, s = tokens.shape
-    h = params["embed"][tokens]
-    positions = jnp.broadcast_to(jnp.arange(s)[None, :], (bsz, s))
+    h, positions = _embed(params, tokens)
     aux_total = jnp.zeros((), jnp.float32)
     mrope_positions = None
 
@@ -350,16 +384,10 @@ def forward(
         )
 
     if at in ("dense", "vlm"):
-        def body(carry, layer):
-            hh = carry
-            hh, _ = _dense_block(
-                hh, layer, positions, cfg, window=window, impl=impl,
-                mrope=cfg.use_mrope, mrope_positions=mrope_positions,
-                unroll=unroll,
-            )
-            return hh, None
-
-        h, _ = _layer_scan(body, h, params["layers"], unroll)
+        h, _ = _dense_layers(
+            params, h, positions, cfg, window=window, impl=impl, kv=False,
+            mrope_positions=mrope_positions, unroll=unroll,
+        )
         if at == "vlm":
             h = h[:, batch["vision_embeds"].shape[1]:]
 
@@ -443,10 +471,7 @@ def forward(
     else:  # pragma: no cover
         raise AssertionError(at)
 
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = h @ head
-    return logits, aux_total
+    return _logits(params, h, cfg), aux_total
 
 
 def _sinusoidal(n: int, d: int) -> jax.Array:
@@ -565,6 +590,34 @@ def init_cache(
     else:  # pragma: no cover
         raise AssertionError(at)
     return cache
+
+
+def prefill(
+    params: Params,
+    cache: Cache,
+    tokens: jax.Array,
+    cfg: ModelConfig,
+) -> Tuple[jax.Array, Cache]:
+    """Whole-prompt prefill of a fresh cache (``pos`` 0): tokens (B, S)
+    int32 → (logits of the last position (B, V), cache holding positions
+    0..S-1 with ``pos`` S).  For the families in ``PREFILL_FAMILIES``; the
+    layers run as in ``forward``, causal over the prompt."""
+    if cfg.arch_type not in PREFILL_FAMILIES:
+        raise ValueError(f"prefill serves {sorted(PREFILL_FAMILIES)}, not "
+                         f"{cfg.arch_type!r}")
+    s = tokens.shape[1]
+    if s > cache["k"].shape[2]:
+        raise ValueError(f"prompt of {s} exceeds cache capacity "
+                         f"{cache['k'].shape[2]}")
+    h, positions = _embed(params, tokens)
+    h, (ks, vs) = _dense_layers(params, h, positions, cfg, window=None,
+                                impl="ref", kv=True)
+    new_cache = dict(cache)
+    for name, new in (("k", ks), ("v", vs)):
+        new_cache[name] = jax.lax.dynamic_update_slice(
+            cache[name], new.astype(cache[name].dtype), (0,) * new.ndim)
+    new_cache["pos"] = jnp.full_like(cache["pos"], s)
+    return _logits(params, h[:, -1], cfg), new_cache
 
 
 def _ring(pos: jax.Array, capacity: int, windowed: bool) -> Tuple[jax.Array, jax.Array]:
@@ -774,6 +827,4 @@ def decode_step(
         raise AssertionError(at)
 
     new_cache["pos"] = pos + 1
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return h @ head, new_cache
+    return _logits(params, h, cfg), new_cache

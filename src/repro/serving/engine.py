@@ -5,8 +5,11 @@ This is the execution-engine layer of the paper's system (§3): each
 *worker* hosts an accelerator-memory model cache (``GpuMemoryManager``)
 and an execution queue; the scheduler from ``make_scheduler`` (Alg. 1
 planning on the host; the served path runs no Alg. 2 adjustment) places
-pipeline tasks; the Execution Engine performs real teacher-forced prefill
-+ autoregressive ``decode_step`` calls on the zoo models.
+pipeline tasks; the Execution Engine runs real prefill + autoregressive
+``decode_step`` calls on the zoo models.  Models of a family in
+``PREFILL_FAMILIES`` (dense) prefill the whole prompt in one ``prefill``
+call; the other families, whose caches ``prefill`` cannot fill yet,
+teacher-force the prompt through ``decode_step`` one position per call.
 
 Every worker shares one device — ``jax.devices()[0]``, a CPU in the tests
 and one TPU chip under ``chip_smoke.py``.  Transfer and fetch *costs*
@@ -47,7 +50,7 @@ from repro.core.scheduler import Scheduler, make_scheduler
 from repro.core.sst_exchange import GossipConfig, GossipPlane
 from repro.core.telemetry import FlightRecorder, TraceConfig
 from repro.core.types import DFG, MLModel, TaskSpec
-from repro.models import decode_step, forward, init_cache, init_params
+from repro.models import PREFILL_FAMILIES, decode_step, init_cache, prefill
 from repro.models.config import ModelConfig
 
 
@@ -108,8 +111,9 @@ class ExecutionEngine:
         self.decode_tokens = decode_tokens
         self.spans = spans if spans is not None else ServedSpans()
         self._steps: Dict[int, Callable] = {}
-        # (model, batch, cache capacity) of every step shape run so far
-        self._shapes_run: Set[Tuple[int, int, int]] = set()
+        self._prefills: Dict[int, Callable] = {}
+        # (model, program, batch, cache capacity) of every call shape run
+        self._shapes_run: Set[Tuple[int, str, int, int]] = set()
 
     def decode_fn(self, mid: int) -> Callable:
         """The jitted one-token step ``(params, cache, tokens) -> (logits,
@@ -125,14 +129,29 @@ class ExecutionEngine:
             self._steps[mid] = jax.jit(served_decode_step)
         return self._steps[mid]
 
-    def _first_call(self, mid: int, batch: int, capacity: int):
-        """A span around the first step call of a shape this engine has
-        not run before (the call that compiles), else nothing."""
-        key = (mid, batch, capacity)
+    def prefill_fn(self, mid: int) -> Callable:
+        """The jitted whole-prompt prefill ``(params, cache, tokens (B, S))
+        -> (last position's logits, cache)`` for model ``mid``, of a family
+        in ``PREFILL_FAMILIES``.  Its program is named
+        ``jit_served_prefill``."""
+        if mid not in self._prefills:
+            cfg = self.models[mid].cfg
+
+            def served_prefill(params, cache, tokens):
+                return prefill(params, cache, tokens, cfg)
+
+            self._prefills[mid] = jax.jit(served_prefill)
+        return self._prefills[mid]
+
+    def _first_call(self, mid: int, program: str, batch: int, capacity: int):
+        """A span around the first call of ``program`` ("prefill" or
+        "decode") at a shape this engine has not run before (the call that
+        compiles), else nothing."""
+        key = (mid, program, batch, capacity)
         if key in self._shapes_run:
             return contextlib.nullcontext()
         self._shapes_run.add(key)
-        return self.spans("first_call", capacity=capacity)
+        return self.spans("first_call", program=program, capacity=capacity)
 
     def run_task(self, mid: int, prompt: np.ndarray) -> Tuple[np.ndarray, float]:
         """Prefill ``prompt`` then greedily decode a few tokens.  Returns
@@ -148,19 +167,27 @@ class ExecutionEngine:
             with sp("task_setup", capacity=cap):
                 cache = init_cache(hosted.cfg, b, capacity=cap)
                 toks = jnp.asarray(prompt)
-            # teacher-forced prefill through the decode path (seeds the
-            # cache); the spans time the dispatch, the device runs later
-            with sp("prefill", calls=s):
-                with self._first_call(mid, b, cap):
-                    logits, cache = step(params, cache, toks[:, 0])
-                for i in range(1, s):
-                    logits, cache = step(params, cache, toks[:, i])
+            # the spans time the dispatch, the device runs later
+            if hosted.cfg.arch_type in PREFILL_FAMILIES:
+                with sp("prefill", calls=1, positions=s):
+                    with self._first_call(mid, "prefill", b, cap):
+                        logits, cache = self.prefill_fn(mid)(params, cache,
+                                                             toks)
+            else:
+                # no whole-prompt fill for these caches yet: teacher-forced
+                # through the decode path, one call per position
+                with sp("prefill", calls=s, positions=s):
+                    with self._first_call(mid, "decode", b, cap):
+                        logits, cache = step(params, cache, toks[:, 0])
+                    for i in range(1, s):
+                        logits, cache = step(params, cache, toks[:, i])
             out = []
             with sp("decode", calls=d):
                 nxt = jnp.argmax(logits, axis=-1)
                 for _ in range(d):
                     out.append(nxt)
-                    logits, cache = step(params, cache, nxt)
+                    with self._first_call(mid, "decode", b, cap):
+                        logits, cache = step(params, cache, nxt)
                     nxt = jnp.argmax(logits, axis=-1)
             with sp("sync"):
                 jax.block_until_ready(logits)

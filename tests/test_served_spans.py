@@ -1,7 +1,9 @@
 """Wall-clock spans of the served path (``ServedSpans`` in
 serving/engine.py, ``FlightRecorder.wall_spans`` in core/telemetry.py):
 nesting, stats, first-call marking, no effect on what is served, nothing
-recorded with tracing off, and the spans' landing in a profiler trace."""
+recorded with tracing off, and the spans' landing in a profiler trace.
+Every test runs a dense model (one prefill call per task) and an SSM one
+(one step call per prompt position)."""
 
 import glob
 import json
@@ -40,10 +42,16 @@ def _chain():
     )
 
 
-@pytest.fixture(scope="module")
-def hosted():
-    cfg = ARCHS["mistral-nemo-12b"].reduced(dtype="float32")
+@pytest.fixture(scope="module", params=["mistral-nemo-12b", "mamba2-780m"])
+def hosted(request):
+    cfg = ARCHS[request.param].reduced(dtype="float32")
     return HostedModel(0, cfg, init_params(cfg, jax.random.key(0)))
+
+
+def _prefill_calls(hosted, prompt):
+    """Program calls of a task's prefill: one for a dense model, one per
+    prompt position for the others."""
+    return 1 if hosted.cfg.arch_type == "dense" else prompt
 
 
 def _cluster(hosted, trace):
@@ -99,14 +107,16 @@ def test_spans_nest_and_share_the_job_id(traced):
                 req.stats["job"]].assignment[task.stats["task"]]
 
 
-def test_calls_match_prompt_and_decode_tokens(traced):
+def test_calls_match_prompt_and_decode_tokens(hosted, traced):
     sc, results = traced
     spans = sc.recorder.wall_spans
     for ti, task in enumerate(spans):
         if task.name != "task":
             continue
         by = {s.name: s for s in _children(spans, ti)}
-        assert by["prefill"].stats["calls"] == task.stats["prompt"]
+        assert by["prefill"].stats["positions"] == task.stats["prompt"]
+        assert by["prefill"].stats["calls"] == _prefill_calls(
+            hosted, task.stats["prompt"])
         assert by["decode"].stats["calls"] == task.stats["decode"] == DECODE
         assert by["readback"].stats["copies"] == DECODE
         assert by["task_setup"].stats["capacity"] == (
@@ -119,15 +129,22 @@ def test_calls_match_prompt_and_decode_tokens(traced):
         s.stats["calls"] for s in spans if s.name == "decode")
 
 
-def test_first_call_marks_each_new_shape_once(traced):
+def test_first_call_marks_each_new_shape_once(hosted, traced):
     sc, _ = traced
     spans = sc.recorder.wall_spans
-    firsts = [(s, spans[s.parent]) for s in spans if s.name == "first_call"]
+    firsts = [(s.stats["job"], s.stats["program"], s.stats["capacity"],
+               spans[s.parent].name) for s in spans if s.name == "first_call"]
     # capacities 5+3+1, 3+3+1 in request 0; none new in request 1; 7+3+1
-    # in request 2
-    assert [(f.stats["job"], f.stats["capacity"]) for f, _ in firsts] == [
-        (0, 9), (0, 7), (2, 11)]
-    assert all(p.name == "prefill" for _, p in firsts)
+    # in request 2.  A dense model compiles its prefill program inside
+    # ``prefill`` and its decode step inside ``decode``; the others run the
+    # decode step from the first prompt position.
+    if hosted.cfg.arch_type == "dense":
+        programs = [("prefill", "prefill"), ("decode", "decode")]
+    else:
+        programs = [("decode", "prefill")]
+    assert firsts == [(job, prog, cap, phase)
+                      for job, cap in ((0, 9), (0, 7), (2, 11))
+                      for prog, phase in programs]
 
 
 def test_wall_clock_reads_are_the_spans(traced):
@@ -220,7 +237,9 @@ def test_spans_and_stats_land_in_the_profiler_trace(hosted, tmp_path):
         "request", "plan", "state", "task", "task_setup", "prefill",
         "decode", "sync", "readback"))
     assert [s["job"] for s in got["compass.request"]] == [r.job_id]
-    assert [s["calls"] for s in got["compass.prefill"]] == [4, DECODE]
+    assert [s["positions"] for s in got["compass.prefill"]] == [4, DECODE]
+    assert [s["calls"] for s in got["compass.prefill"]] == [
+        _prefill_calls(hosted, 4), _prefill_calls(hosted, DECODE)]
     assert [s["calls"] for s in got["compass.decode"]] == [DECODE, DECODE]
     assert {s["task"] for s in got["compass.task"]} == {"a", "b"}
     assert all(s["job"] == r.job_id for v in got.values() for s in v)
@@ -229,9 +248,14 @@ def test_spans_and_stats_land_in_the_profiler_trace(hosted, tmp_path):
 def test_step_program_has_a_stable_name(hosted):
     from repro.models import init_cache
 
+    def name(fn, tokens):
+        text = fn.lower(hosted.params, cache, tokens).as_text()
+        return text.split("{", 1)[0].split("@", 1)[1].split()[0]
+
     sc = _cluster(hosted, trace=False)
     cache = init_cache(hosted.cfg, 1, capacity=8)
-    text = sc.engine.decode_fn(0).lower(
-        hosted.params, cache, np.zeros((1,), np.int32)).as_text()
-    assert text.split("{", 1)[0].split("@", 1)[1].split()[0] == \
+    assert name(sc.engine.decode_fn(0), np.zeros((1,), np.int32)) == \
         "jit_served_decode_step"
+    if hosted.cfg.arch_type == "dense":
+        assert name(sc.engine.prefill_fn(0), np.zeros((1, 4), np.int32)) == \
+            "jit_served_prefill"

@@ -8,6 +8,7 @@ described inside a fixture, so only the process that runs this file loads
 the TPU library.
 """
 
+import dataclasses
 import os
 
 import jax
@@ -23,7 +24,7 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.moe_gmm import moe_gmm
 from repro.kernels.ssd_scan import ssd_scan
 from repro.launch.mesh import make_mesh
-from repro.models import abstract_params, decode_step, init_cache
+from repro.models import abstract_params, decode_step, init_cache, prefill
 from repro.training import make_serve_step
 
 
@@ -119,6 +120,28 @@ def test_mamba2_780m_decode_step_compiles_for_v5e(one_chip):
     )
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 1.5e9  # ~1.6 GB of bf16 weights
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_nemo_12b_s10_prefill_compiles_for_v5e(one_chip):
+    """The served whole-prompt prefill of the benchmark's nemo-12b stage
+    (10 of 40 layers, bf16) at its longest prompt, 256, on one chip."""
+    cfg = dataclasses.replace(get_config("mistral-nemo-12b"), n_layers=10,
+                              dtype="bfloat16")
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            tree,
+        )
+
+    params = on_chip(abstract_params(cfg))
+    cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, 1, 256 + 9)))
+    tokens = jax.ShapeDtypeStruct((1, 256), jnp.int32, sharding=one_chip)
+    compiled = _compile(lambda p, c, t: prefill(p, c, t, cfg),
+                        params, cache, tokens)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 8e9  # ~8.1 GB of bf16 weights
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
