@@ -2,6 +2,8 @@
 
 Everything a cell needs is found by name under ``benchmarks/chip/``:
 ``configs/<config>.json``, ``traffic/<mix>.json``,
-``metrics/<metric>.py`` and ``reference/<family>.py``.  Adding a cell,
-a mix, a metric or a reference is adding a file.
+``metrics/<metric>.py``, ``reference/<family>.py`` and
+``families/<family>.py`` (a model family's weight leaves, program keys and
+work counts).  Adding a cell, a mix, a metric, a reference or a model
+family is adding a file.
 """

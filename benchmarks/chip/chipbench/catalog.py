@@ -1,5 +1,6 @@
 """Find the pieces of a cell by name: BENCHMARK.json, configurations,
-traffic mixes, per-layer metric readers, plain references and peaks."""
+traffic mixes, per-layer metric readers, plain references, model families
+and peaks."""
 
 from __future__ import annotations
 
@@ -68,6 +69,18 @@ def metric_reader(name: str, bench_dir: Path = BENCH_DIR) -> ModuleType:
 def reference(family: str, bench_dir: Path = BENCH_DIR) -> ModuleType:
     return _module(bench_dir / "reference" / f"{family}.py",
                    f"chipbench_reference_{family}")
+
+
+def family(name: str, bench_dir: Path = BENCH_DIR) -> ModuleType:
+    """``families/<name>.py``: one model family's ``ARCH_TYPE`` (the
+    program's ``arch_type``), ``PROGRAM_KEYS`` (its configuration keys ->
+    ``ModelConfig`` fields), ``leaves(m)`` ({path: (shape, dtype, kind)} of
+    the layer stack, whose bytes ``work.leaf_bytes`` counts),
+    ``decode_stack(m, context)`` and, optional, ``init(kind, key, shape,
+    dtype)`` for its own weight kinds and ``prefill_stack(m, prompt)``.
+    Counts are (FLOPs, bytes) at batch 1."""
+    return _module(bench_dir / "families" / f"{name}.py",
+                   f"chipbench_family_{name}")
 
 
 def peaks(device_kind: str, bench_dir: Path = BENCH_DIR) -> Dict[str, Any]:

@@ -34,17 +34,11 @@ DRAIN_LIMIT_S = 60.0
 # Host spans the breakdown attributes idle device time to, innermost first.
 HOST_LABELS = ("plan", "task", "idle", "submit")
 
-_PROGRAM_KEYS = {  # configuration key -> ModelConfig field
-    "common": {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
-               "vocab_size": "vocab", "tie_word_embeddings": "tie_embeddings",
-               "rms_norm_eps": "norm_eps", "dtype": "dtype"},
-    "ssm": {"state_size": "ssm_state", "head_dim": "ssm_head_dim",
-            "expand": "ssm_expand", "conv_kernel": "conv_kernel",
-            "n_groups": "ssm_groups"},
-    "dense": {"num_attention_heads": "n_heads",
-              "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
-              "intermediate_size": "d_ff", "rope_theta": "rope_theta"},
-}
+# Configuration key -> ModelConfig field, for every family; each family
+# adds its own (``families/<family>.py``).
+_PROGRAM_KEYS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
+                 "vocab_size": "vocab", "tie_word_embeddings": "tie_embeddings",
+                 "rms_norm_eps": "norm_eps", "dtype": "dtype"}
 
 
 @dataclasses.dataclass
@@ -80,11 +74,12 @@ def program_config(conf: Dict[str, Any]):
     cfg = dataclasses.replace(get_config(prog["arch"]),
                               dtype=conf["model"]["dtype"],
                               **prog.get("overrides", {}))
-    keys = dict(_PROGRAM_KEYS["common"], **_PROGRAM_KEYS[conf["family"]])
+    fam = catalog.family(conf["family"])
+    keys = dict(_PROGRAM_KEYS, **fam.PROGRAM_KEYS)
     wrong = {k: (v, getattr(cfg, keys[k])) for k, v in conf["model"].items()
              if k in keys and getattr(cfg, keys[k]) != v}
-    if cfg.arch_type != conf["family"]:
-        wrong["family"] = (conf["family"], cfg.arch_type)
+    if cfg.arch_type != fam.ARCH_TYPE:
+        wrong["arch_type"] = (fam.ARCH_TYPE, cfg.arch_type)
     if wrong:
         raise ValueError(f"program config differs from {conf['name']}: {wrong}")
     return cfg

@@ -1,11 +1,12 @@
-"""Operations and HBM bytes of one whole-prompt prefill call (batch 1) of
-a dense configuration, counted as ``work.py`` counts a decode step: the
-least the call has to do, whatever implements it.
+"""Operations and HBM bytes of one whole-prompt prefill call (batch 1),
+counted as ``work.py`` counts a decode step: the least the call has to
+do, whatever implements it.
 
 * every layer weight is read once, whatever the prompt's length; of the
   embedding table only the prompt's rows count; an untied head counts
   whole (it runs at the last position only);
-* the prompt's keys and values are written once into the cache;
+* the layer stack, its cache written, counts as its family's
+  ``prefill_stack`` states it; a family without one has no such call;
 * the last position's logits are written once, in the served dtype.
 """
 
@@ -13,7 +14,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Tuple
 
-from chipbench import work
+from chipbench import catalog, work
 
 # The served path's prefill program (``ExecutionEngine.prefill_fn``), as the
 # profiler names its executions.
@@ -22,23 +23,19 @@ PROGRAM = "jit_served_prefill"
 
 def prefill_call(m: Dict[str, Any], family: str,
                  prompt: int) -> Tuple[float, float]:
-    """(FLOPs, HBM bytes) of one prefill of ``prompt`` positions: position
-    p attends causally over p + 1 positions, the head runs once."""
-    if family != "dense":
+    """(FLOPs, HBM bytes) of one prefill of ``prompt`` positions through a
+    family's ``prefill_stack``; the head runs once."""
+    fam = catalog.family(family)
+    if not hasattr(fam, "prefill_stack"):
         raise ValueError(f"no whole-prompt prefill for family {family!r}")
-    w = work._itemsize(m)
-    d, l, v = m["hidden_size"], m["num_hidden_layers"], m["vocab_size"]
-    # _layer_flops is linear in the context: the mean context of a causal
-    # prompt, (prompt + 1) / 2, times the prompt's positions
-    flops = l * prompt * work._layer_flops(m, family, (prompt + 1) / 2.0)
+    w = work.itemsize(m)
+    d, v = m["hidden_size"], m["vocab_size"]
+    flops, nbytes = fam.prefill_stack(m, prompt)
     flops += 2 * d * v
-    kv = m["num_key_value_heads"] * m["head_dim"] * w * 2  # k and v
-    nbytes = (l * work._layer_bytes(m, family)
-              + d * w  # final norm
-              + prompt * d * w  # the embedding rows gathered
-              + d * v * w  # head, or the whole table when tied
-              + l * kv * prompt  # the cache written
-              + v * w)  # logits out
+    nbytes += (d * w  # final norm
+               + prompt * d * w  # the embedding rows gathered
+               + d * v * w  # head, or the whole table when tied
+               + v * w)  # logits out
     return float(flops), float(nbytes)
 
 

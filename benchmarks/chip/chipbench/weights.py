@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import catalog
+
 F32 = jnp.float32
 
 
@@ -24,9 +26,10 @@ def seed_words(seed: int, stream: int) -> np.ndarray:
     return ss.generate_state(4, np.uint32)
 
 
-def _shapes(m: Dict[str, Any], family: str) -> Dict[str, Any]:
-    """{path: (shape, dtype, kind)} of every leaf."""
-    d, l, v = m["hidden_size"], m["num_hidden_layers"], m["vocab_size"]
+def _shapes(m: Dict[str, Any], fam) -> Dict[str, Any]:
+    """{path: (shape, dtype, kind)} of every leaf: the embedding, final norm
+    and head here, the layer stack as family ``fam`` states it."""
+    d, v = m["hidden_size"], m["vocab_size"]
     wd = jnp.dtype(m["dtype"])
     leaves = {
         "embed": ((v, d), wd, "normal"),
@@ -34,44 +37,11 @@ def _shapes(m: Dict[str, Any], family: str) -> Dict[str, Any]:
     }
     if not m["tie_word_embeddings"]:
         leaves["lm_head"] = ((d, v), wd, "normal")
-    if family == "ssm":
-        di = m["expand"] * d
-        h = di // m["head_dim"]
-        gn = m["n_groups"] * m["state_size"]
-        c = di + 2 * gn
-        k = m["conv_kernel"]
-        leaves.update({
-            "layers/ln": ((l, d), wd, "norm"),
-            "layers/w_in": ((l, d, 2 * di + 2 * gn + h), wd, "normal"),
-            "layers/conv_w": ((l, k, c), wd, "conv"),
-            "layers/conv_b": ((l, c), wd, "conv"),
-            "layers/dt_bias": ((l, h), F32, "dt_bias"),
-            "layers/a_log": ((l, h), F32, "a_log"),
-            "layers/d_skip": ((l, h), F32, "norm"),
-            "layers/gate_norm": ((l, di), wd, "norm"),
-            "layers/w_out": ((l, di, d), wd, "out"),
-        })
-    elif family == "dense":
-        hd = m["head_dim"]
-        hq, kv = m["num_attention_heads"], m["num_key_value_heads"]
-        f = m["intermediate_size"]
-        leaves.update({
-            "layers/ln1": ((l, d), wd, "norm"),
-            "layers/ln2": ((l, d), wd, "norm"),
-            "layers/wq": ((l, d, hq * hd), wd, "normal"),
-            "layers/wk": ((l, d, kv * hd), wd, "normal"),
-            "layers/wv": ((l, d, kv * hd), wd, "normal"),
-            "layers/wo": ((l, hq * hd, d), wd, "out"),
-            "layers/mlp/wg": ((l, d, f), wd, "normal"),
-            "layers/mlp/wu": ((l, d, f), wd, "normal"),
-            "layers/mlp/wd": ((l, f, d), wd, "out"),
-        })
-    else:
-        raise ValueError(f"unknown family {family!r}")
+    leaves.update(fam.leaves(m))
     return leaves
 
 
-def _leaf(key, shape, dtype, kind: str, n_layers: int):
+def _leaf(key, shape, dtype, kind: str, n_layers: int, fam):
     if kind == "normal":
         return (jax.random.normal(key, shape, F32) * 0.02).astype(dtype)
     if kind == "out":
@@ -79,14 +49,8 @@ def _leaf(key, shape, dtype, kind: str, n_layers: int):
         return (jax.random.normal(key, shape, F32) * sc).astype(dtype)
     if kind == "norm":
         return (1.0 + 0.1 * jax.random.normal(key, shape, F32)).astype(dtype)
-    if kind == "conv":
-        return jax.random.uniform(key, shape, F32, -0.5, 0.5).astype(dtype)
-    if kind == "a_log":  # A = -exp(a_log) in -[1, 16]
-        return jnp.log(jax.random.uniform(key, shape, F32, 1.0, 16.0))
-    if kind == "dt_bias":  # softplus(dt_bias) = dt, log-uniform [1e-3, 1e-1]
-        dt = jnp.exp(jax.random.uniform(
-            key, shape, F32, math.log(1e-3), math.log(1e-1)))
-        return dt + jnp.log(-jnp.expm1(-dt))
+    if hasattr(fam, "init"):
+        return fam.init(kind, key, shape, dtype)
     raise ValueError(kind)
 
 
@@ -104,7 +68,8 @@ def _nest(flat: Dict[str, Any]) -> Dict[str, Any]:
 def make(model: Dict[str, Any], family: str, seed: int):
     """The parameter tree for ``model`` from ``seed``, on the default
     device, in one jitted call."""
-    leaves = _shapes(model, family)
+    fam = catalog.family(family)
+    leaves = _shapes(model, fam)
     names = sorted(leaves)
     n_layers = model["num_hidden_layers"]
 
@@ -113,7 +78,7 @@ def make(model: Dict[str, Any], family: str, seed: int):
         for i, name in enumerate(names):
             shape, dtype, kind = leaves[name]
             flat[name] = _leaf(jax.random.fold_in(key, i), shape, dtype, kind,
-                               n_layers)
+                               n_layers, fam)
         return _nest(flat)
 
     key = jax.random.wrap_key_data(jnp.asarray(seed_words(seed, 0)),
@@ -125,7 +90,8 @@ def abstract(model: Dict[str, Any], family: str):
     """ShapeDtypeStructs of ``make``'s tree, allocating nothing."""
     return _nest({
         name: jax.ShapeDtypeStruct(shape, dtype)
-        for name, (shape, dtype, _) in _shapes(model, family).items()
+        for name, (shape, dtype, _) in _shapes(
+            model, catalog.family(family)).items()
     })
 
 

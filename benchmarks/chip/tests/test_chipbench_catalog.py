@@ -23,6 +23,7 @@ def test_new_files_are_found_by_name(tmp_path):
     (bench_dir / "configs" / "other.json").write_text(json.dumps(
         {"model": {"hidden_size": 8}}))
     (bench_dir / "reference" / "hybrid.py").write_text("FAMILY = 'hybrid'\n")
+    (bench_dir / "families" / "hybrid.py").write_text("ARCH_TYPE = 'hybrid'\n")
     bench = catalog.benchmark()
     bench["configs"].append({"name": "other",
                              "file": "benchmarks/chip/configs/other.json"})
@@ -34,6 +35,7 @@ def test_new_files_are_found_by_name(tmp_path):
     new = catalog.benchmark(tmp_path)
     assert catalog.config("other", new, tmp_path)["model"]["hidden_size"] == 8
     assert catalog.reference("hybrid", bench_dir).FAMILY == "hybrid"
+    assert catalog.family("hybrid", bench_dir).ARCH_TYPE == "hybrid"
 
 
 def test_peaks_by_device_kind():
